@@ -26,12 +26,46 @@ from __future__ import annotations
 
 import sys
 import time
+import traceback
+
+
+def run_modules(modules) -> list:
+    """Run each ``(name, module)`` and print its CSV rows; returns the
+    names of the modules whose ``run()`` raised (each prints an
+    ``<name>_ERROR`` row and its traceback, and the sweep carries on)."""
+    failed = []
+    for name, mod in modules:
+        t0 = time.time()
+        try:
+            rows = mod.run()
+        except Exception as e:  # noqa: BLE001
+            print(f"{name}_ERROR,0,{type(e).__name__}: {e}")
+            traceback.print_exc()
+            failed.append(name)
+            continue
+        for row_name, us, derived in rows:
+            print(f"{row_name},{us:.1f},{derived}")
+        print(f"{name}_wall_s,{(time.time()-t0)*1e6:.0f},total", file=sys.stderr)
+    return failed
 
 
 def main() -> None:
+    from repro.runtime import enable_compilation_cache
+
+    from . import compare, trace_scale
+
+    enable_compilation_cache()
+    # The committed results are the regression baseline; the modules
+    # overwrite them in place, so snapshot first.
+    baseline_dir = compare.snapshot_results()
+    print("name,us_per_call,derived")
+    # trace_scale replays in a child process (so ru_maxrss is that replay
+    # alone), and a child cannot reach a chip this process holds. Importing
+    # the scheduler initialises JAX's backend, so trace_scale runs before
+    # the other modules are imported.
+    failed = run_modules([("trace_scale", trace_scale)])
     from . import (
         algo_runtime,
-        compare,
         kernel_bench,
         migration_quality,
         migrations,
@@ -43,10 +77,9 @@ def main() -> None:
         round_pipeline,
         serving_latency,
         sweep_bench,
-        trace_scale,
     )
 
-    modules = [
+    failed += run_modules([
         ("perf_models", perf_models),
         ("placement_quality", placement_quality),
         ("algo_runtime", algo_runtime),
@@ -57,28 +90,15 @@ def main() -> None:
         ("response_time", response_time),
         ("sweep_bench", sweep_bench),
         ("round_pipeline", round_pipeline),
-        ("trace_scale", trace_scale),
         ("kernel_bench", kernel_bench),
         ("obs_overhead", obs_overhead),
-    ]
-    # The committed results are the regression baseline; the modules
-    # overwrite them in place, so snapshot first.
-    baseline_dir = compare.snapshot_results()
-    print("name,us_per_call,derived")
-    for name, mod in modules:
-        t0 = time.time()
-        try:
-            rows = mod.run()
-        except Exception as e:  # noqa: BLE001
-            print(f"{name}_ERROR,0,{type(e).__name__}: {e}")
-            continue
-        for row_name, us, derived in rows:
-            print(f"{row_name},{us:.1f},{derived}")
-        print(f"{name}_wall_s,{(time.time()-t0)*1e6:.0f},total", file=sys.stderr)
+    ])
     csv_rows, regressions = compare.run(baseline_dir)
     for row_name, us, derived in csv_rows:
         print(f"{row_name},{us:.1f},{derived}")
-    if regressions:
+    if failed:
+        print(f"benchmark modules failed: {', '.join(failed)}", file=sys.stderr)
+    if regressions or failed:
         sys.exit(1)
 
 

@@ -8,7 +8,15 @@ full in-memory series) and asserts the replay stays under a committed
 peak-RSS and wall-clock gate.
 
 The replay runs in a **subprocess** so ``ru_maxrss`` measures this replay
-alone, not whatever benchmark ran earlier in the harness process. The
+alone, not whatever benchmark ran earlier in the harness process. On an
+accelerator that child needs the chip, which one process holds at a time,
+so the parent must not have touched JAX's devices (`_run_child` refuses
+otherwise). Run it on its own with
+
+    PYTHONPATH=src python -m benchmarks.trace_scale
+
+(whose parent never imports the scheduler); `benchmarks/run.py` runs it
+before importing any other module. The
 paper-scale configuration (``REPRO_BENCH_SCALE=paper``) is the paper's
 evaluation setup: 12,500 machines (48/rack, 16 racks/pod), 24h, 0.6 slot
 utilisation — ~10^5 jobs / ~10^6 tasks admitted from hourly windows. The
@@ -141,6 +149,9 @@ def _child_main(payload: dict) -> None:
 
 
 def _run_child(payload: dict) -> dict:
+    from repro.runtime import require_chip_free
+
+    require_chip_free("trace_scale")
     env = dict(os.environ)
     src = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "src")
     env["PYTHONPATH"] = src + (

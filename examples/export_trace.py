@@ -25,6 +25,7 @@ import os
 import sys
 
 from repro import obs
+from repro.runtime import enable_compilation_cache
 from repro.core import latency, simulator, topology, workload
 from repro.core.policy import PolicyParams
 
@@ -93,4 +94,5 @@ def main(outdir: str = ".") -> None:
 
 
 if __name__ == "__main__":
+    enable_compilation_cache()
     main(sys.argv[1] if len(sys.argv) > 1 else ".")

@@ -19,6 +19,7 @@ from repro.data import DataConfig, SyntheticLMData
 from repro.launch.mesh import make_mesh
 from repro.launch.train import reduce_config
 from repro import configs
+from repro.runtime import enable_compilation_cache
 from repro.models import LM
 from repro.optim import AdamW, AdamWConfig
 from repro.train import steps as train_steps
@@ -65,5 +66,6 @@ def train_demo():
 
 
 if __name__ == "__main__":
+    enable_compilation_cache()
     schedule_demo()
     train_demo()

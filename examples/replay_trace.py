@@ -21,6 +21,7 @@ import argparse
 from repro.core import latency, topology
 from repro.core.simulator import SimConfig, Simulator
 from repro.core.trace import synth_trace
+from repro.runtime import enable_compilation_cache
 
 
 def main() -> None:
@@ -63,4 +64,5 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    enable_compilation_cache()
     main()

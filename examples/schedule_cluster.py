@@ -10,6 +10,7 @@ import numpy as np
 from repro.core import latency, simulator, topology, workload
 from repro.core.policy import PolicyParams
 from repro.launch.schedule import ARCH_KIND, schedule_ml_jobs
+from repro.runtime import enable_compilation_cache
 
 
 def failure_demo():
@@ -41,6 +42,7 @@ def failure_demo():
 
 
 if __name__ == "__main__":
+    enable_compilation_cache()
     print("=== NoMora-scheduled ML fleet ===")
     placements, metrics = schedule_ml_jobs(n_machines=128, n_jobs=8, duration_s=240)
     s = metrics.summary()

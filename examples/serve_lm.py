@@ -4,9 +4,11 @@ Run:  PYTHONPATH=src python examples/serve_lm.py
 """
 
 from repro.launch import serve as serve_launch
+from repro.runtime import enable_compilation_cache
 
 
 if __name__ == "__main__":
+    enable_compilation_cache()
     serve_launch.main(
         [
             "--arch", "qwen3-0.6b", "--reduce", "8",

@@ -17,6 +17,7 @@ import sys
 
 from repro.core.scenarios import SCENARIOS
 from repro.core.sweep import SweepSpec, run_sweep
+from repro.runtime import enable_compilation_cache
 
 
 def main() -> None:
@@ -48,4 +49,5 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    enable_compilation_cache()
     main()

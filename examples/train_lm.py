@@ -10,6 +10,7 @@ import shutil
 import tempfile
 
 from repro.launch import train as train_launch
+from repro.runtime import enable_compilation_cache
 
 
 def main():
@@ -43,4 +44,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compilation_cache()
     main()

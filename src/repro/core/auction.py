@@ -315,6 +315,8 @@ def solve_transportation(
     locked = np.arange(S)[None, :] >= machine_capacity[:, None]
     price0[locked] = float(PRICE_LOCK)
 
+    # The host reference bids through the jnp top-2, never the Pallas
+    # kernel, so on a TPU it stays independent of the device path.
     price, _, assigned, iters = _auction_phase(
         jnp.asarray(price0),
         jnp.asarray(vm_p),
@@ -323,6 +325,7 @@ def solve_transportation(
         jnp.asarray(active),
         jnp.float32(eps),
         max_iters_per_phase,
+        use_pallas=False,
     )
     total_iters = int(iters)
     if total_iters >= max_iters_per_phase:

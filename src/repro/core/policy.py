@@ -24,9 +24,10 @@ from task t to machine m costs exactly
 The (T, M+J) matrix (last J columns are the per-job unscheduled
 aggregators) is materialised by two interchangeable paths:
 
-- `dense_costs` — the **host reference**: numpy end to end (the costmap
-  kernel's output is pulled back with `np.asarray`). This is the oracle the
-  parity suite and the explicit-graph MCMF (flow_network.py) consume.
+- `dense_costs` — the **host reference**: numpy end to end, Eq. 6 through
+  the jnp LUT path on every platform (never the Pallas kernel). This is the
+  oracle the parity suite and the explicit-graph MCMF (flow_network.py)
+  consume.
 - `dense_costs_device` / `device_round_costs` — the **fused on-device
   path**: one jitted jnp program running costmap (Pallas or jnp LUT) →
   rack segment-max (Eq. 8) → p_m/p_r/b thresholding → preemption-discount
@@ -126,12 +127,22 @@ def machine_costs(
     perf_idx: np.ndarray,
     task_root_latency: np.ndarray,
 ) -> np.ndarray:
-    """d_{t,m} for every task x machine (Eq. 6). Uses the costmap kernel."""
+    """d_{t,m} for every task x machine (Eq. 6) through the LUT reference.
+
+    Always the jnp LUT path, never the Pallas kernel: this feeds the host
+    reference (`dense_costs`, hence ``auction_host``, ``mcmf`` and
+    `reference_sim`), which must stay independent of the kernel it checks
+    on every platform — on a TPU an auto-selected kernel would make the
+    reference compare the device with itself.
+    """
     from repro.kernels.costmap import ops as costmap_ops
 
     return np.asarray(
         costmap_ops.costmap(
-            lut_table, jnp.asarray(perf_idx), jnp.asarray(task_root_latency)
+            lut_table,
+            jnp.asarray(perf_idx),
+            jnp.asarray(task_root_latency),
+            use_pallas=False,
         )
     )
 

@@ -358,6 +358,14 @@ class RoundProgram:
         branch at the exact row shape real rounds will carry; otherwise a
         host (1, M) zero block exercises the numpy branch only.
         """
+        window = self._synthetic_window(free_slots, root_latency)
+        with obs.span("round_program.warmup", bucket_tasks=self.n_pad_tasks):
+            self.advance(self.init_state(np.asarray(free_slots)), window)
+
+    def _synthetic_window(self, free_slots, root_latency=None) -> RoundWindow:
+        """One throwaway round at this bucket: a single task of job 0
+        rooted on machine 0, zero latency unless ``root_latency`` is
+        given."""
         M = self.n_machines
         state = RoundState(
             task_job=np.zeros(1, np.int64),
@@ -373,14 +381,12 @@ class RoundProgram:
             cur_machine=np.full(1, -1, np.int64),
             free_slots=np.asarray(free_slots, np.int32),
         )
-        window = stack_round_states(
+        return stack_round_states(
             [state],
             n_pad_tasks=self.n_pad_tasks,
             n_pad_jobs=self.n_pad_jobs,
             exact=self.exact,
         )
-        with obs.span("round_program.warmup", bucket_tasks=self.n_pad_tasks):
-            self.advance(self.init_state(state.free_slots), window)
 
     def _round_body(
         self, free_slots, inputs, *, p_m, p_r, omega, gamma, preemption,
@@ -522,6 +528,44 @@ class RoundProgram:
         return jax.vmap(one)(variant_params, variant_active)
 
     # ------------------------------------------------------------------ #
+
+    def _arg_shapes(self, sharding=None):
+        """Shape-only stand-ins for `advance`'s arguments on one R=1
+        round at this bucket: the carry, the window arrays and the policy
+        scalars, placed by ``sharding``."""
+        window = self._synthetic_window(np.zeros(self.n_machines, np.int32))
+        args = (
+            self.init_state(window.free_slots[0]),
+            self._window_arrays(window),
+            self._params_scalars(self.params),
+        )
+        return jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding),
+            args,
+        )
+
+    def lower_window(self, sharding=None):
+        """Lower the R=1 window program (what `advance` runs for one
+        round) from shapes alone. ``sharding`` places it on a device that
+        need not be attached — e.g. one chip of a described TPU topology —
+        so ``.compile().as_text()`` shows which kernels it really calls."""
+        return self._advance_jit.lower(*self._arg_shapes(sharding))
+
+    def lower_whatif(self, n_lanes: int, sharding=None):
+        """Lower the ``n_lanes``-wide what-if program from shapes alone
+        (see `lower_window`)."""
+
+        def sds(shape, dtype):
+            return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+        _state, window, scalars = self._arg_shapes(sharding)
+        return self._whatif_jit.lower(
+            sds(window[7].shape[1:], window[7].dtype),
+            tuple(sds(a.shape[1:], a.dtype) for a in window[:7]),
+            {k: sds((n_lanes,), v.dtype) for k, v in scalars.items()},
+            sds((n_lanes, self.n_pad_tasks), jnp.bool_),
+            sds((), jnp.int32),
+        )
 
     def _check_cost_bound(
         self, window: RoundWindow, variants: Optional[Sequence[PolicyParams]] = None

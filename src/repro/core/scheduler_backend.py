@@ -484,6 +484,12 @@ class WindowedAuctionBackend(AuctionBackend):
         self._states: dict = {}  # (Tp, Jp, chain) -> DeviceRoundState
         self._pin = (0, 0)  # serving bucket floor (Tp, Jp); (0, 0) = unpinned
 
+    @property
+    def programs(self) -> dict:
+        """The round programs built so far, keyed (task bucket, job
+        bucket, chained)."""
+        return self._programs
+
     def pin_serving(self, n_tasks: int, n_jobs: int) -> None:
         """Pin the (task, job) bucket floor for long-lived serving.
 

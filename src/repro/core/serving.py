@@ -26,12 +26,13 @@ What makes this a new contract rather than a driver loop:
   ladder and reports the largest rate whose queue still drains — the
   knee before queue blow-up — reusing ONE warmed backend across rungs so
   the sweep itself stays recompile-free.
-- **Parity with batch replay.** With ``record_rounds > 0`` the service
+- **Parity with the reference.** With ``record_rounds > 0`` the service
   snapshots the first K solver rounds (exact `RoundState` + chosen
-  columns) and `verify_replay` re-solves them through a fresh per-round
-  ``auction`` backend: placements must be bit-identical (the windowed
-  program's parity contract, now exercised through the warm serving
-  path with pinned, padded buckets).
+  columns) and `verify_replay` re-solves them through a fresh
+  ``auction_host`` backend — numpy Eq. 6-10 on the LUT path and the jnp
+  auction, no Pallas kernel on any platform: placements must be
+  bit-identical (the windowed program's parity contract, exercised
+  through the warm serving path with pinned, padded buckets).
 
 Wall-clock timestamps only enter the *measured* latencies; simulated
 dynamics (admission, retirement, queue evolution) run on the simulator's
@@ -136,7 +137,7 @@ class ServingReport:
         }
 
 
-class _RoundRecorder:
+class RoundRecorder:
     """Transparent backend wrapper capturing the first K solver rounds.
 
     Delegates everything (flags included) to the wrapped backend via
@@ -262,9 +263,9 @@ class ScheduleService:
             # first real decision pays neither.
             warm_rows = self.sim.oracle.root_rows(np.zeros(1, np.int64), 0)
         self.sim.backend.warm_serving(self.sim.free_slots, root_latency=warm_rows)
-        self.recorder: Optional[_RoundRecorder] = None
+        self.recorder: Optional[RoundRecorder] = None
         if cfg.record_rounds > 0:
-            self.recorder = _RoundRecorder(self.sim.backend, cfg.record_rounds)
+            self.recorder = RoundRecorder(self.sim.backend, cfg.record_rounds)
             self.sim.backend = self.recorder
 
     # ------------------------------------------------------------------ #
@@ -394,34 +395,41 @@ class ScheduleService:
     # ------------------------------------------------------------------ #
 
     def verify_replay(self) -> int:
-        """Re-solve recorded serving rounds through a fresh per-round
-        ``auction`` backend; returns the count of rounds whose placements
-        differ (the windowed program's bit-parity contract, exercised
-        through the warm pinned path). -1 when nothing was recorded or
-        the serving backend is not auction-family (baseline backends
-        draw from the simulator's shared rng stream, which a fresh
-        replay cannot reproduce)."""
+        """Re-solve recorded serving rounds through a fresh
+        ``auction_host`` backend (the reference: no Pallas kernel, so on
+        a TPU the device path is checked against an independent solve);
+        returns the count of rounds whose placements differ. -1 when
+        nothing was recorded or the serving backend is not auction-family
+        (baseline backends draw from the simulator's shared rng stream,
+        which a fresh replay cannot reproduce)."""
         if self.recorder is None or not self.recorder.records:
             return -1
         if not self.cfg.backend.startswith("auction"):
             return -1
-        ref = make_backend(
-            "auction", self.cfg.params, self.cfg.topology(), self.sim.lut
+        return replay_mismatches(
+            self.recorder.records, self.cfg.params, self.cfg.topology(),
+            self.sim.lut,
         )
-        mismatches = 0
-        for state, cols in self.recorder.records:
-            ctx = RoundContext(
-                rng=np.random.default_rng(0),
-                task_counts=np.zeros(self.cfg.n_machines, np.int64),
-                n_ready=state.n_tasks,
-            )
-            ref_cols = np.asarray(ref.place(state, ctx).cols, np.int64)
-            if not np.array_equal(ref_cols, cols):
-                mismatches += 1
-        return mismatches
 
 
 # --------------------------------------------------------------------- #
+
+
+def replay_mismatches(records, params: PolicyParams, topo: Topology, lut) -> int:
+    """Count recorded ``(RoundState, cols)`` rounds whose placement differs
+    from a fresh ``auction_host`` solve of the same round."""
+    ref = make_backend("auction_host", params, topo, lut)
+    mismatches = 0
+    for state, cols in records:
+        ctx = RoundContext(
+            rng=np.random.default_rng(0),
+            task_counts=np.zeros(topo.n_machines, np.int64),
+            n_ready=state.n_tasks,
+        )
+        ref_cols = np.asarray(ref.place(state, ctx).cols, np.int64)
+        if not np.array_equal(ref_cols, cols):
+            mismatches += 1
+    return mismatches
 
 
 def serve(cfg: ServingConfig, **overrides) -> ServingReport:
@@ -455,7 +463,7 @@ def saturation_sweep(
         )
         if share_backend and shared is None:
             inner = svc.sim.backend
-            while isinstance(inner, _RoundRecorder):
+            while isinstance(inner, RoundRecorder):
                 inner = inner._inner
             shared = inner
         report = svc.run()

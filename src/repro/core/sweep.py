@@ -338,6 +338,8 @@ def run_sweep(
     `spec.cells()` grid order regardless of completion order. The spawn
     context avoids forking a process with live XLA state; each worker pays
     one JAX import on startup, amortised across its share of the grid.
+    Workers are JAX processes: on an accelerator each would need the chip,
+    so the pool refuses to start in a process that already holds one.
 
     ``shard=(i, n)`` runs only the ``i``-th of ``n`` deterministic
     contiguous slices of the grid (multi-host partitioning; composes with
@@ -353,6 +355,9 @@ def run_sweep(
     cells: List[SweepCell] = []
     try:
         if workers > 1 and len(jobs) > 1:
+            from repro.runtime import require_chip_free
+
+            require_chip_free("run_sweep(workers>1)")
             ctx = multiprocessing.get_context("spawn")
             with ctx.Pool(processes=min(workers, len(jobs))) as pool:
                 # imap preserves submission order => deterministic merge.

@@ -2,7 +2,7 @@
 
 Each kernel package has:
   kernel.py  - pl.pallas_call + explicit BlockSpec VMEM tiling (TPU target)
-  ops.py     - jit'd public wrapper (auto CPU fallback / interpret mode)
+  ops.py     - jit'd public wrapper (jnp path off TPU / interpret mode)
   ref.py     - pure-jnp oracle used by tests
 
 Paper-side kernels (the scheduler's hot spots, DESIGN.md §4):
@@ -15,9 +15,3 @@ Data-plane kernels (the scheduled workloads' hot spots):
   rwkv6_scan        - RWKV-6 data-dependent-decay linear recurrence
   rglru_scan        - RG-LRU gated linear recurrence (RecurrentGemma)
 """
-
-from jax.experimental.pallas import tpu as _pltpu
-
-# jax renamed TPUCompilerParams -> CompilerParams; support both so the
-# kernels track the installed jax rather than one side of the rename.
-CompilerParams = getattr(_pltpu, "CompilerParams", None) or _pltpu.TPUCompilerParams

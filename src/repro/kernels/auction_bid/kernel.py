@@ -24,8 +24,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import CompilerParams
-
 NEG_INF = float(-(2.0**62))
 DEFAULT_BT = 256
 DEFAULT_BC = 512
@@ -39,8 +37,12 @@ def _bid_kernel(values_ref, price1_ref, price2_ref, idx_ref, best_ref, second_re
     v2 = values_ref[...] - price2_ref[...]
 
     tile_best = jnp.max(v1, axis=1, keepdims=True)  # (BT, 1)
-    tile_arg = jnp.argmax(v1, axis=1)  # (BT,)
     cols = jax.lax.broadcasted_iota(jnp.int32, v1.shape, 1)
+    # Lowest column among the row's maxima — jnp.argmax's tie rule, which
+    # the host reference and the auction's parity rely on. The TPU's
+    # native arg-max reduction picks another tied column (measured on a
+    # v5e), and integer-valued bids tie all the time.
+    tile_arg = jnp.min(jnp.where(v1 == tile_best, cols, bc), axis=1)  # (BT,)
     is_arg = cols == tile_arg[:, None]
     runner_other = jnp.max(jnp.where(is_arg, NEG_INF, v1), axis=1, keepdims=True)
     runner_same = jnp.max(jnp.where(is_arg, v2, NEG_INF), axis=1, keepdims=True)
@@ -106,7 +108,7 @@ def bid_top2_pallas(
             jax.ShapeDtypeStruct((T, 1), jnp.float32),
             jax.ShapeDtypeStruct((T, 1), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")
         ),
         interpret=interpret,

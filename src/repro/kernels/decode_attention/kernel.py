@@ -17,8 +17,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import CompilerParams
-
 DEFAULT_BK = 1024
 NEG_INF = -1e30
 
@@ -105,7 +103,7 @@ def decode_attention_pallas(
             pltpu.VMEM((1, 1), jnp.float32),
             pltpu.VMEM((1, D), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")
         ),
         interpret=interpret,

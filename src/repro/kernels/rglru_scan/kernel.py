@@ -17,8 +17,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import CompilerParams
-
 DEFAULT_BT = 256
 DEFAULT_BD = 512
 
@@ -92,7 +90,7 @@ def rglru_scan_pallas(
             jax.ShapeDtypeStruct((B, D), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((1, bd), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")
         ),
         interpret=interpret,

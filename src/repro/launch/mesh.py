@@ -13,26 +13,18 @@ import jax
 import numpy as np
 
 
-def _axis_types_kwargs(n_axes: int) -> dict:
-    """jax.sharding.AxisType only exists on newer jax (explicit-sharding
-    API); older versions default every axis to Auto, so omitting the
-    kwarg there is equivalent."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * n_axes}
-
-
 def make_production_mesh(*, multi_pod: bool = False):
     """Single pod: 16x16 = 256 chips (data, model); multi-pod: 2 pods."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **_axis_types_kwargs(len(axes)))
+    return make_mesh(shape, axes)
 
 
 def make_mesh(shape: Sequence[int], axes: Sequence[str]):
     return jax.make_mesh(
-        tuple(shape), tuple(axes), **_axis_types_kwargs(len(axes))
+        tuple(shape),
+        tuple(axes),
+        axis_types=(jax.sharding.AxisType.Auto,) * len(axes),
     )
 
 

@@ -20,6 +20,7 @@ import numpy as np
 from repro.core import latency, simulator, topology, workload
 from repro.core.policy import PolicyParams
 from repro.launch.mesh import nomora_ordered_devices
+from repro.runtime import enable_compilation_cache
 
 
 ARCH_KIND = {
@@ -96,6 +97,7 @@ def schedule_ml_jobs(
 
 
 def main(argv=None):
+    enable_compilation_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--machines", type=int, default=192)
     ap.add_argument("--jobs", type=int, default=12)

@@ -69,11 +69,24 @@ def test_auction_bid_kernel_matches_ref(T, C):
     ri, rb, rs = bid_ref.bid_top2_ref(values, price1, price2)
     np.testing.assert_array_equal(np.asarray(gb), np.asarray(rb))
     np.testing.assert_array_equal(np.asarray(gs), np.asarray(rs))
-    # argmax index may differ on exact value ties; check value equivalence.
-    v1 = np.asarray(values) - np.asarray(price1)[None, :]
-    np.testing.assert_array_equal(
-        v1[np.arange(T), np.asarray(gi)], v1[np.arange(T), np.asarray(ri)]
+    np.testing.assert_array_equal(np.asarray(gi), np.asarray(ri))
+
+
+@pytest.mark.parametrize("block_c", [128, 512])
+def test_auction_bid_kernel_breaks_ties_to_lowest_column(block_c):
+    # Integer-valued bids tie constantly; the winner must be the lowest
+    # tied column (jnp.argmax's rule), within a tile and across tiles.
+    rng = np.random.default_rng(7)
+    T, C = 16, 1024
+    values = jnp.asarray(rng.integers(-4, 0, size=(T, C)).astype(np.float32))
+    price1 = jnp.asarray(rng.integers(0, 2, size=C).astype(np.float32))
+    price2 = price1 + 1
+    gi, gb, gs = bid_kernel.bid_top2_pallas(
+        values, price1, price2, block_c=block_c, interpret=True
     )
+    ri, rb, rs = bid_ref.bid_top2_ref(values, price1, price2)
+    for g, r in ((gi, ri), (gb, rb), (gs, rs)):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(r))
 
 
 def test_auction_bid_single_column_second_is_slot2():

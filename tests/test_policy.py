@@ -133,3 +133,38 @@ def test_baseline_policies_feasible(rng):
     placed2 = out2[out2 >= 0]
     counts2 = np.bincount(placed2, minlength=16)
     assert np.all(counts2 <= free)
+
+
+def test_host_reference_never_calls_the_kernels(monkeypatch):
+    """On a TPU the host reference (`dense_costs`, ``auction_host``) must
+    still run Eq. 6 through the LUT and bid through jnp, or it would check
+    the device kernels against themselves."""
+    import jax
+
+    from repro.core.scheduler_backend import RoundContext, make_backend
+    from repro.kernels.auction_bid import kernel as bid_kernel
+    from repro.kernels.costmap import kernel as cm_kernel
+    from repro.kernels.costmap import ops as costmap_ops
+
+    def kernel_called(*_args, **_kwargs):
+        raise AssertionError("the host reference called a Pallas kernel")
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(cm_kernel, "costmap_pallas", kernel_called)
+    monkeypatch.setattr(bid_kernel, "bid_top2_pallas", kernel_called)
+    state = _state(np.random.default_rng(3), T=9, J=3, preempt_running=True)
+    # Control: the auto-selecting op does take the kernel on a "TPU".
+    with pytest.raises(AssertionError, match="Pallas kernel"):
+        costmap_ops.costmap(
+            LUT, state.perf_idx, state.root_latency[state.task_job]
+        )
+    params = policy.PolicyParams(preemption=True)
+    costs = policy.dense_costs(state, TOPO, params, LUT)
+    assert costs.d.shape == (9, TOPO.n_machines)
+    ctx = RoundContext(
+        rng=np.random.default_rng(0),
+        task_counts=np.zeros(TOPO.n_machines, np.int64),
+        n_ready=state.n_tasks,
+    )
+    placed = make_backend("auction_host", params, TOPO, LUT).place(state, ctx)
+    assert len(placed.cols) == 9
