@@ -627,37 +627,49 @@ class RoundProgram:
         )
 
     def _record_window_spans(
-        self, t0_ns: int, window: RoundWindow, iters_np: np.ndarray
+        self,
+        t0_ns: int,
+        sync: Tuple[int, int],
+        window: RoundWindow,
+        iters_np: np.ndarray,
     ) -> None:
-        """Reconstruct per-round sub-slices of one fused window dispatch.
+        """Record the ``round_program.advance`` span and its synthetic
+        per-round sub-slices, after the fact.
 
-        The scanned window is a single XLA program — no host code runs
-        between rounds, so individual rounds cannot be clocked directly.
-        Instead the dispatch wall time is split across rounds
-        proportionally to each round's auction iteration count (scan
-        metadata the program already returns) and recorded as synthetic
-        sub-slices nested inside one ``round_program.advance`` span.
+        ``advance`` runs from ``t0_ns`` (before the upload) to now (after
+        the sync) and carries the bucket shape and each round's auction
+        iterations in its args. It is recorded before its sub-slices, so a
+        reader walking the span list meets the bucket first.
+
+        The ``round_program.round`` sub-slices are synthetic: the scanned
+        window is one XLA program, so no host code runs between rounds.
+        They split the ``sync`` interval (device run plus wait) by each
+        round's iteration count and nest inside the live
+        ``round_program.sync`` span. Their times measure nothing; they are
+        kept only for the ``iterations`` arg the kernel roofline readers
+        take.
         """
         t1_ns = time.perf_counter_ns()
         R = window.n_rounds
-        total_ns = t1_ns - t0_ns
+        iters = iters_np.astype(np.int64).reshape(-1)[:R]
         obs.record_span(
             "round_program.advance",
             t0_ns,
-            total_ns,
+            t1_ns - t0_ns,
             {"rounds": R, "bucket_tasks": self.n_pad_tasks,
-             "bucket_jobs": self.n_pad_jobs},
+             "bucket_jobs": self.n_pad_jobs,
+             "iterations": [int(i) for i in iters]},
         )
-        iters = iters_np.astype(np.int64).reshape(-1)[:R]
         obs.add("window.rounds", R)
         obs.add("auction.iterations", int(iters.sum()))
         obs.add(
             "auction.pad_waste_tasks",
             sum(self.n_pad_tasks - T for T in window.n_tasks),
         )
+        s0, s1 = sync
         weights = np.maximum(iters.astype(np.float64), 1.0)
-        edges = t0_ns + np.round(
-            np.cumsum(np.concatenate([[0.0], weights])) / weights.sum() * total_ns
+        edges = s0 + np.round(
+            np.cumsum(np.concatenate([[0.0], weights])) / weights.sum() * (s1 - s0)
         ).astype(np.int64)
         for r in range(R):
             obs.record_span(
@@ -678,35 +690,53 @@ class RoundProgram:
         (donated on supporting backends) and the advanced state returned.
         Host-side validation (convergence, iteration caps, float32 cost
         bounds) happens around the dispatch, never inside it.
+
+        Spans: ``round_program.upload`` (the transfers), ``.dispatch`` (the
+        jitted call returning), ``.sync`` (the iteration counts coming
+        back: device run plus wait) and ``.fetch`` (the other results and
+        the checks).
         """
         self._check_cost_bound(window)
         telemetry = obs.enabled()
         if telemetry:
             obs.add("h2d.upload_bytes", self._window_upload_bytes(window))
             t0_ns = time.perf_counter_ns()
-        new_state, (assigned, iters, cost, true_cost) = self._advance_jit(
-            state, self._window_arrays(window), self._params_scalars(self.params)
-        )
-        iters_np = np.asarray(iters)
-        if telemetry:
-            self._record_window_spans(t0_ns, window, iters_np)
-        if int(iters_np.max(initial=0)) >= self.max_iters:
-            raise RuntimeError(
-                f"auction hit the iteration cap ({self.max_iters}) inside the window"
+        with obs.span("round_program.upload"):
+            arrs = self._window_arrays(window)
+            scalars = self._params_scalars(self.params)
+            if telemetry:  # the span then measures the transfers
+                jax.block_until_ready((arrs, scalars))
+        with obs.span("round_program.dispatch"):
+            new_state, (assigned, iters, cost, true_cost) = self._advance_jit(
+                state, arrs, scalars
             )
-        assigned_np = np.asarray(assigned)
-        for r, T in enumerate(window.n_tasks):
-            if (assigned_np[r, :T] < 0).any():
+        with obs.span("round_program.sync"):
+            if telemetry:
+                s0_ns = time.perf_counter_ns()
+            iters_np = np.asarray(iters)
+            if telemetry:
+                s1_ns = time.perf_counter_ns()
+        if telemetry:
+            self._record_window_spans(t0_ns, (s0_ns, s1_ns), window, iters_np)
+        with obs.span("round_program.fetch"):
+            if int(iters_np.max(initial=0)) >= self.max_iters:
                 raise RuntimeError(
-                    f"auction did not converge in round {r}: unassigned tasks remain"
+                    f"auction hit the iteration cap ({self.max_iters}) inside the window"
                 )
-        return new_state, WindowResult(
-            assigned=assigned_np,
-            iterations=iters_np,
-            per_task_cost=np.asarray(cost),
-            per_task_true_cost=np.asarray(true_cost),
-            n_tasks=window.n_tasks,
-        )
+            assigned_np = np.asarray(assigned)
+            for r, T in enumerate(window.n_tasks):
+                if (assigned_np[r, :T] < 0).any():
+                    raise RuntimeError(
+                        f"auction did not converge in round {r}: unassigned tasks remain"
+                    )
+            result = WindowResult(
+                assigned=assigned_np,
+                iterations=iters_np,
+                per_task_cost=np.asarray(cost),
+                per_task_true_cost=np.asarray(true_cost),
+                n_tasks=window.n_tasks,
+            )
+        return new_state, result
 
     def what_if(
         self,
@@ -733,12 +763,13 @@ class RoundProgram:
         """
         if not variants:
             raise ValueError("what_if needs at least one PolicyParams variant")
-        window = stack_round_states(
-            [state],
-            n_pad_tasks=self.n_pad_tasks,
-            n_pad_jobs=self.n_pad_jobs,
-            exact=self.exact,
-        )
+        with obs.span("round_program.stack"):
+            window = stack_round_states(
+                [state],
+                n_pad_tasks=self.n_pad_tasks,
+                n_pad_jobs=self.n_pad_jobs,
+                exact=self.exact,
+            )
         self._check_cost_bound(window, variants)
         K = len(variants)
         T = window.n_tasks[0]
@@ -752,38 +783,43 @@ class RoundProgram:
                 )
             masks[:, : active_masks.shape[1]] = active_masks
         scale = int(window.scale[0])
-        arrs = self._window_arrays(window)
-        round_arrays = tuple(a[0] for a in arrs[:7])
-        free_slots = arrs[7][0]
-        if obs.enabled():
+        telemetry = obs.enabled()
+        if telemetry:
             obs.add("h2d.upload_bytes", self._window_upload_bytes(window))
             obs.add("whatif.lanes", K)
-        with obs.span("round_program.whatif", lanes=K, n_tasks=T):
+        with obs.span("round_program.upload"):
+            arrs = self._window_arrays(window)
+            lane_args = (
+                _pad_params(variants), jnp.asarray(masks), jnp.int32(scale)
+            )
+            if telemetry:  # the span then measures the transfers
+                jax.block_until_ready((arrs, lane_args))
+        with obs.span("round_program.dispatch"):
+            round_arrays = tuple(a[0] for a in arrs[:7])
+            free_slots = arrs[7][0]
             assigned, iters, cost, true_cost, stay_cost = self._whatif_jit(
-                free_slots,
-                round_arrays,
-                _pad_params(variants),
-                jnp.asarray(masks),
-                jnp.int32(scale),
+                free_slots, round_arrays, *lane_args
             )
+        with obs.span("round_program.sync"):
             iters_np = np.asarray(iters)
-        if obs.enabled():
+        if telemetry:
             obs.add("auction.iterations", int(iters_np.astype(np.int64).sum()))
-        if int(iters_np.max(initial=0)) >= self.max_iters:
-            raise RuntimeError(
-                f"auction hit the iteration cap ({self.max_iters}) in a what-if lane"
+        with obs.span("round_program.fetch"):
+            if int(iters_np.max(initial=0)) >= self.max_iters:
+                raise RuntimeError(
+                    f"auction hit the iteration cap ({self.max_iters}) in a what-if lane"
+                )
+            assigned_np = np.asarray(assigned)
+            if ((assigned_np[:, :T] < 0) & masks[:, :T]).any():
+                raise RuntimeError(
+                    "auction did not converge in a what-if lane: unassigned tasks remain"
+                )
+            return WhatIfResult(
+                assigned=assigned_np,
+                iterations=iters_np,
+                per_task_cost=np.asarray(cost),
+                per_task_true_cost=np.asarray(true_cost),
+                per_task_stay_cost=np.asarray(stay_cost),
+                n_tasks=T,
+                active_masks=masks if active_masks is not None else None,
             )
-        assigned_np = np.asarray(assigned)
-        if ((assigned_np[:, :T] < 0) & masks[:, :T]).any():
-            raise RuntimeError(
-                "auction did not converge in a what-if lane: unassigned tasks remain"
-            )
-        return WhatIfResult(
-            assigned=assigned_np,
-            iterations=iters_np,
-            per_task_cost=np.asarray(cost),
-            per_task_true_cost=np.asarray(true_cost),
-            per_task_stay_cost=np.asarray(stay_cost),
-            n_tasks=T,
-            active_masks=masks if active_masks is not None else None,
-        )

@@ -554,12 +554,13 @@ class WindowedAuctionBackend(AuctionBackend):
         from .round_program import stack_round_states
 
         key, prog = self._program(state.n_tasks, state.n_jobs)
-        window = stack_round_states(
-            [state],
-            n_pad_tasks=prog.n_pad_tasks,
-            n_pad_jobs=prog.n_pad_jobs,
-            exact=self.exact,
-        )
+        with obs.span("round_program.stack"):
+            window = stack_round_states(
+                [state],
+                n_pad_tasks=prog.n_pad_tasks,
+                n_pad_jobs=prog.n_pad_jobs,
+                exact=self.exact,
+            )
         dstate = self._state_for(key, prog, state.free_slots)
         with solver_clock("solver.auction_windowed") as clk:
             dstate, res = prog.advance(dstate, window)
@@ -591,12 +592,13 @@ class WindowedAuctionBackend(AuctionBackend):
             max(s.n_jobs for s in states),
             chain=chain,
         )
-        window = stack_round_states(
-            states,
-            n_pad_tasks=prog.n_pad_tasks,
-            n_pad_jobs=prog.n_pad_jobs,
-            exact=self.exact,
-        )
+        with obs.span("round_program.stack", rounds=len(states)):
+            window = stack_round_states(
+                states,
+                n_pad_tasks=prog.n_pad_tasks,
+                n_pad_jobs=prog.n_pad_jobs,
+                exact=self.exact,
+            )
         if chain:
             # Round 0's row becomes the delta on the freshly-seeded carry.
             dstate = prog.init_state(states[0].free_slots)

@@ -689,37 +689,38 @@ class Simulator:
         """One scheduling round: build RoundState, let the backend place."""
         cfg = self.cfg
         backend = self.backend
-        if backend.caps_admission:
-            # Admit at most (free capacity + slack) tasks per round: a large
-            # backlog against a full cluster degenerates the auction into
-            # unscheduled-price wars (Firmament likewise schedules what
-            # fits; the remainder waits with escalating unscheduled cost).
-            admit = min(cfg.max_round_tasks, int(self.free_slots.sum()) + 64)
-        else:
-            admit = cfg.max_round_tasks
-        pos, ready_ids = self._ready_prefix(admit)
-        mover_ids = EMPTY_IDS
-        # Not redundant with run()'s migration_round gate: straggler rounds
-        # OR into the flag without consulting the backend. Seed semantics:
-        # every solver-family backend feeds movers into the round (for
-        # random_solver their presence even shifts the rng stream) and
-        # clears the straggler set, but only migration-capable backends
-        # later apply the mover columns; the two §6.1 heuristics do neither.
-        degraded: Dict[int, float] = {}
-        if migration_round and backend.selects_movers:
-            if self.qos is not None:
-                # Continuous controller: only QoS-degraded jobs' tasks are
-                # migration candidates (the trigger window already debounced
-                # them; healthy jobs are never churned).
-                degraded = self.qos.degraded_jobs()
-                mover_ids = (
-                    self._select_movers(restrict_jobs=degraded)
-                    if degraded
-                    else EMPTY_IDS
-                )
+        with obs.span("sim.select"):
+            if backend.caps_admission:
+                # Admit at most (free capacity + slack) tasks per round: a large
+                # backlog against a full cluster degenerates the auction into
+                # unscheduled-price wars (Firmament likewise schedules what
+                # fits; the remainder waits with escalating unscheduled cost).
+                admit = min(cfg.max_round_tasks, int(self.free_slots.sum()) + 64)
             else:
-                mover_ids = self._select_movers()
-            self._straggler_jobs.clear()
+                admit = cfg.max_round_tasks
+            pos, ready_ids = self._ready_prefix(admit)
+            mover_ids = EMPTY_IDS
+            # Not redundant with run()'s migration_round gate: straggler rounds
+            # OR into the flag without consulting the backend. Seed semantics:
+            # every solver-family backend feeds movers into the round (for
+            # random_solver their presence even shifts the rng stream) and
+            # clears the straggler set, but only migration-capable backends
+            # later apply the mover columns; the two §6.1 heuristics do neither.
+            degraded: Dict[int, float] = {}
+            if migration_round and backend.selects_movers:
+                if self.qos is not None:
+                    # Continuous controller: only QoS-degraded jobs' tasks are
+                    # migration candidates (the trigger window already debounced
+                    # them; healthy jobs are never churned).
+                    degraded = self.qos.degraded_jobs()
+                    mover_ids = (
+                        self._select_movers(restrict_jobs=degraded)
+                        if degraded
+                        else EMPTY_IDS
+                    )
+                else:
+                    mover_ids = self._select_movers()
+                self._straggler_jobs.clear()
         if not len(ready_ids) and not len(mover_ids):
             # A migration round with zero eligible movers still samples the
             # migrated-percentage series (0%): dropping it silently would

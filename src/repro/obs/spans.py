@@ -38,6 +38,14 @@ process recompiles what a warm one reuses) and are therefore excluded
 from deterministic snapshots (`deterministic_counters`) — per-cell sweep
 telemetry must be identical between full and sharded runs.
 
+Profiler mirror: `set_enabled(True)` also lazily imports
+``jax.profiler.TraceAnnotation``, and while telemetry is on every live
+`span` opens an annotation of the same name. Under a running
+``jax.profiler`` trace the program's spans then sit on the profile's
+host plane, on the same clock as the device's XLA ops (outside a trace an
+annotation adds about a microsecond to a live span). `record_span` slices are
+after-the-fact reconstructions and stay registry-only.
+
 Buffers are bounded (`MAX_SPANS` etc.); overflow increments
 ``dropped_spans`` / ``dropped_samples`` / ``dropped_audit`` rather than
 silently truncating, and `export.summarize` surfaces the drop counts.
@@ -90,16 +98,23 @@ _NULL_SPAN = _NullSpan()
 
 
 class _Span:
-    """Live span handle; records itself into the registry on exit."""
+    """Live span handle; records itself into the registry on exit.
 
-    __slots__ = ("_tel", "name", "args", "_t0_ns")
+    ``mirror`` (a ``jax.profiler.TraceAnnotation``-like factory, or None)
+    opens a profiler annotation of the same name around the span, entered
+    before its clock starts and left after it stops."""
 
-    def __init__(self, tel: "Telemetry", name: str, args):
+    __slots__ = ("_tel", "name", "args", "_t0_ns", "_mirror")
+
+    def __init__(self, tel: "Telemetry", name: str, args, mirror=None):
         self._tel = tel
         self.name = name
         self.args = args
+        self._mirror = mirror(name) if mirror is not None else None
 
     def __enter__(self) -> "_Span":
+        if self._mirror is not None:
+            self._mirror.__enter__()
         self._tel._stack().append(self)
         self._t0_ns = time.perf_counter_ns()
         return self
@@ -124,6 +139,8 @@ class _Span:
                 self.args,
             )
         )
+        if self._mirror is not None:
+            self._mirror.__exit__(*exc)
         return False
 
 
@@ -167,8 +184,10 @@ class Telemetry:
 
     # -------------------------------------------------------------- #
 
-    def span(self, name: str, args: Optional[Dict[str, Any]] = None) -> _Span:
-        return _Span(self, name, args)
+    def span(
+        self, name: str, args: Optional[Dict[str, Any]] = None, mirror=None
+    ) -> _Span:
+        return _Span(self, name, args, mirror)
 
     def _append_span(self, rec: SpanRecord) -> None:
         with self._lock:
@@ -249,6 +268,9 @@ _enabled = os.environ.get("REPRO_OBS", "0").strip().lower() not in (
 )
 _telemetry = Telemetry()
 _jit_hook_installed = False
+# ``jax.profiler.TraceAnnotation`` once telemetry has been enabled (None
+# before, or where jax is absent): live spans mirror onto the profiler.
+_mirror = None
 
 
 def enabled() -> bool:
@@ -260,7 +282,7 @@ def set_enabled(on: bool) -> None:
     global _enabled
     _enabled = bool(on)
     if _enabled:
-        _install_jit_hook()
+        _install_hooks()
 
 
 def get() -> Telemetry:
@@ -274,7 +296,7 @@ def reset() -> None:
 def span(name: str, **args: Any):
     if not _enabled:
         return _NULL_SPAN
-    return _telemetry.span(name, args or None)
+    return _telemetry.span(name, args or None, _mirror)
 
 
 def record_span(name, t0_ns, dur_ns, args=None, depth=0) -> None:
@@ -329,9 +351,23 @@ def scope(reset_registry: bool = True) -> Iterator[Telemetry]:
         set_enabled(prev)
 
 
+def _install_hooks() -> None:
+    """Hook telemetry into jax once per process (lazy: jax never imports
+    unless telemetry is actually enabled): the profiler mirror and the
+    jit-cache-miss listener."""
+    global _mirror
+    if _mirror is None:
+        try:
+            from jax.profiler import TraceAnnotation
+        except ImportError:  # jax absent: spans stay registry-only
+            pass
+        else:
+            _mirror = TraceAnnotation
+    _install_jit_hook()
+
+
 def _install_jit_hook() -> None:
-    """Register the jit-cache-miss listener once per process (lazy: jax
-    never imports unless telemetry is actually enabled)."""
+    """Register the jit-cache-miss listener once per process."""
     global _jit_hook_installed
     if _jit_hook_installed:
         return
@@ -350,4 +386,4 @@ def _install_jit_hook() -> None:
 
 
 if _enabled:  # env-enabled process (REPRO_OBS=1): hook up front
-    _install_jit_hook()
+    _install_hooks()

@@ -411,3 +411,109 @@ def test_export_acceptance_controller_replay(tmp_path):
     assert c.get("qos.triggers", 0) > 0
     assert c.get("sim.tasks_migrated", 0) == metrics.tasks_migrated
     assert c.get("controller.rounds", 0) == metrics.controller_rounds
+
+
+# --------------------------------------------------------------------- #
+# Solver and host-round spans: profiler mirror, off path, span order
+
+SOLVER_PARTS = (
+    "round_program.upload",
+    "round_program.dispatch",
+    "round_program.sync",
+    "round_program.fetch",
+)
+
+
+def _windowed_sim(duration_s=30):
+    """A short replay through the windowed device backend (one R=1 window
+    per solver round) at a small M."""
+    topo = topology.Topology(
+        n_machines=64, machines_per_rack=8, racks_per_pod=4,
+        slots_per_machine=4,
+    )
+    plane = latency.LatencyPlane.synthesize(topo, duration_s=duration_s, seed=0)
+    wl = workload.synth_workload(
+        topo, duration_s=duration_s, seed=1, target_utilisation=0.35
+    )
+    cfg = simulator.SimConfig(policy="nomora", backend="auction_windowed", seed=3)
+    return simulator.Simulator(wl, plane, cfg)
+
+
+def test_spans_mirror_onto_profiler_host_plane(tmp_path):
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+
+    sim = _windowed_sim()
+    with obs.scope():
+        with jax.profiler.trace(str(tmp_path)):
+            sim.run()
+    (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    host = {}  # name -> [(start_ns, end_ns)] on the host plane
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                for e in line.events:
+                    host.setdefault(e.name, []).append(
+                        (e.start_ns, e.start_ns + e.duration_ns)
+                    )
+
+    def inside(iv, outers):
+        return any(a <= iv[0] and iv[1] <= b for a, b in outers)
+
+    rounds = host["sim.round"]
+    solver = [iv for iv in host["solver.auction_windowed"] if inside(iv, rounds)]
+    assert len(solver) == len(host["solver.auction_windowed"]) == sim.metrics.rounds
+    for part in SOLVER_PARTS:
+        assert len(host[part]) == len(solver), part
+        assert all(inside(iv, solver) for iv in host[part]), part
+    for name in ("sim.select", "sim.build_state", "round_program.stack", "sim.apply"):
+        assert host[name] and all(inside(iv, rounds) for iv in host[name]), name
+    # After-the-fact records stay in the registry alone.
+    assert "round_program.advance" not in host
+    assert "round_program.round" not in host
+
+
+def test_disabled_solver_spans_add_no_device_sync(monkeypatch):
+    import jax
+
+    from repro.obs import spans
+
+    calls = []
+    real = jax.block_until_ready
+
+    def counted(x):
+        calls.append(1)
+        return real(x)
+
+    monkeypatch.setattr(jax, "block_until_ready", counted)
+    sim = _windowed_sim()
+    sim.run()
+    assert sim.metrics.rounds > 0
+    assert calls == []
+    assert obs.span("round_program.sync") is spans._NULL_SPAN
+    # On, the upload span waits for its transfers: once per solver round.
+    sim = _windowed_sim()
+    with obs.scope():
+        sim.run()
+    assert len(calls) == sim.metrics.rounds
+
+
+def test_advance_span_precedes_its_round_records():
+    sim = _windowed_sim()
+    with obs.scope() as tel:
+        sim.run()
+        total = obs.counters()["auction.iterations"]
+    spans = list(tel.spans)
+    starts = [i for i, s in enumerate(spans) if s.name == "round_program.advance"]
+    assert len(starts) == sim.metrics.rounds
+    iters = 0
+    for i, j in zip(starts, starts[1:] + [len(spans)]):
+        adv = spans[i]
+        rounds = [s for s in spans[i + 1 : j] if s.name == "round_program.round"]
+        assert len(rounds) == adv.args["rounds"] == len(adv.args["iterations"])
+        assert [r.args["iterations"] for r in rounds] == adv.args["iterations"]
+        iters += sum(adv.args["iterations"])
+    assert not any(s.name == "round_program.round" for s in spans[: starts[0]])
+    assert iters == total
