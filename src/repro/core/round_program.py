@@ -17,7 +17,9 @@ This module keeps the round state *resident on device* and advances it with
   silently copies).
 - `RoundWindow` — one window's exogenous inputs, stacked `(R, ...)` on the
   bucketed shapes `(Tp, Jp)` shared by every round of the window (built by
-  `stack_round_states` from per-round `policy.RoundState` records).
+  `stack_round_states` from per-round `policy.RoundState` records). Host
+  latency rows stop at the window's own row bucket `Jr <= Jp`; the program
+  pads them to `Jp` on the device after the upload.
 - `RoundProgram` — compiles the window program once per bucket shape and
   runs it: each scanned round inlines the *pure* step functions
   (`policy.cost_round_step` → Eq. 7 preemption discount →
@@ -104,12 +106,14 @@ class RoundWindow:
     ``chain_slots=False`` and per-round *deltas* under ``chain_slots=True``.
     ``scale`` is the per-round auction cost scale ((T+1) exact, else 1).
     ``n_tasks`` / ``wait_max`` stay on host for result slicing and the
-    float32-exactness guard.
+    float32-exactness guard. Host ``root_latency`` holds ``Jr`` rows, the
+    row bucket of the window's largest round (``Jr <= Jp``); device rows
+    are already ``(R, Jp, M)``.
     """
 
     task_job: np.ndarray  # (R, Tp) i32
     perf_idx: np.ndarray  # (R, Tp) i32
-    root_latency: np.ndarray  # (R, Jp, M) f32
+    root_latency: np.ndarray  # (R, Jr, M) f32 on host; (R, Jp, M) on device
     wait_s: np.ndarray  # (R, Tp) f32
     run_s: np.ndarray  # (R, Tp) f32
     cur_machine: np.ndarray  # (R, Tp) i32
@@ -214,16 +218,25 @@ def stack_round_states(
     Mirrors `policy.device_round_costs`'s padding exactly (task_job/perf
     pads to 0, cur_machine to -1, latency rows to 0) so real rows are
     bit-identical to the per-round path regardless of bucket size.
+
+    Host latency rows are stacked only up to ``Jr``, the row bucket of the
+    window's largest round (capped at ``Jp``): `RoundProgram` appends the
+    ``Jp - Jr`` zero rows on the device, so a round under a large pinned
+    bucket neither zero-fills nor uploads rows it does not have.
     """
     R = len(states)
     if R == 0:
         raise ValueError("empty round window")
     Tp, Jp = n_pad_tasks, n_pad_jobs
     M = states[0].n_machines
+    device_latency = isinstance(states[0].root_latency, jax.Array)
+    Jr = Jp if device_latency else min(
+        auction._bucket(max(s.root_latency.shape[0] for s in states), 8), Jp
+    )
     out = RoundWindow(
         task_job=np.zeros((R, Tp), np.int32),
         perf_idx=np.zeros((R, Tp), np.int32),
-        root_latency=np.zeros((R, Jp, M), np.float32),
+        root_latency=np.zeros((R, Jr, M), np.float32),
         wait_s=np.zeros((R, Tp), np.float32),
         run_s=np.zeros((R, Tp), np.float32),
         cur_machine=np.full((R, Tp), -1, np.int32),
@@ -246,7 +259,6 @@ def stack_round_states(
     # the scatter copies whatever is there, up to the window bucket. Rows
     # past the round's real jobs are never indexed by a real task
     # (task_job < n_jobs), so they are as inert as zero padding.
-    device_latency = isinstance(states[0].root_latency, jax.Array)
     for r, s in enumerate(states):
         T, J = s.n_tasks, s.n_jobs
         if T > Tp or J > Jp or s.root_latency.shape[0] > Jp:
@@ -275,13 +287,23 @@ def stack_round_states(
     return out
 
 
+@functools.partial(jax.jit, static_argnums=1)
+def _pad_rows(rows, n_pad_jobs: int):
+    """(R, Jr, M) latency rows -> (R, Jp, M), zero rows appended on the
+    device: the block the host would otherwise build and ship, bit for
+    bit. One small program per (Jr, Jp); the round programs keep their
+    (R, Jp, M) input."""
+    return jnp.pad(rows, ((0, 0), (0, n_pad_jobs - rows.shape[1]), (0, 0)))
+
+
 class RoundProgram:
     """Compiled persistent window program for one (Tp, Jp, M) bucket.
 
     Holds the device-resident round-invariant inputs (perf LUT, tie-jitter
-    matrix) and the jitted scan/vmap programs; `advance` consumes and
-    returns a `DeviceRoundState` (donated where the backend supports it),
-    `what_if` fans one round out over K `PolicyParams` variants.
+    matrix, policy scalars) and the jitted scan/vmap programs; `advance`
+    consumes and returns a `DeviceRoundState` (donated where the backend
+    supports it), `what_if` fans one round out over K `PolicyParams`
+    variants.
     """
 
     def __init__(
@@ -319,6 +341,17 @@ class RoundProgram:
         self.jitter = auction._jitter_device(
             self.n_pad_tasks, self.n_machines, self.tie_jitter
         )
+        # The policy scalars are fixed for the program, so they too go up
+        # once: six scalar transfers a round took about 2.6 ms on a TPU
+        # v5e, more than the whole window's arrays.
+        self.scalars = jax.device_put(dict(
+            p_m=np.int32(params.p_m),
+            p_r=np.int32(params.p_r),
+            omega=np.float32(params.omega),
+            gamma=np.float32(params.gamma),
+            preemption=np.bool_(params.preemption),
+            beta_scale=np.float32(params.beta_scale),
+        ))
         # Buffer donation keeps the carry in place across windows; CPU has
         # no donation support, so skip it there to avoid per-call warnings.
         donate = (0,) if jax.default_backend() != "cpu" else ()
@@ -355,8 +388,10 @@ class RoundProgram:
         ``root_latency`` optionally substitutes the latency rows — pass a
         device array (e.g. a pinned `DeviceLatencyOracle.root_rows`
         output) to also compile `stack_round_states`'s device-scatter
-        branch at the exact row shape real rounds will carry; otherwise a
-        host (1, M) zero block exercises the numpy branch only.
+        branch at the exact row shape real rounds will carry, or a host
+        block of fewer than ``Jp`` rows to also compile the device-side
+        row pad for its row bucket; otherwise a host (Jp, M) zero block
+        is uploaded whole, as a round with ``Jp`` jobs is.
         """
         window = self._synthetic_window(free_slots, root_latency)
         with obs.span("round_program.warmup", bucket_tasks=self.n_pad_tasks):
@@ -372,7 +407,7 @@ class RoundProgram:
             perf_idx=np.zeros(1, np.int64),
             root_machine=np.zeros(1, np.int64),
             root_latency=(
-                np.zeros((1, M), np.float32)
+                np.zeros((self.n_pad_jobs, M), np.float32)
                 if root_latency is None
                 else root_latency
             ),
@@ -537,7 +572,7 @@ class RoundProgram:
         args = (
             self.init_state(window.free_slots[0]),
             self._window_arrays(window),
-            self._params_scalars(self.params),
+            self.scalars,
         )
         return jax.tree.map(
             lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding),
@@ -587,8 +622,19 @@ class RoundProgram:
                         f"{bound} * {scale} * 4 >= 2^24"
                     )
 
+    def _count_upload(self, window: RoundWindow) -> None:
+        """Telemetry of one window's upload: ``h2d.upload_bytes`` and
+        ``h2d.latency_rows_skipped``, the zero latency rows the device
+        pads on instead of the host shipping them (R x (Jp - Jr))."""
+        obs.add("h2d.upload_bytes", self._window_upload_bytes(window))
+        obs.add(
+            "h2d.latency_rows_skipped",
+            window.n_rounds * (self.n_pad_jobs - window.root_latency.shape[1]),
+        )
+
     def _window_upload_bytes(self, window: RoundWindow) -> int:
-        """Host bytes `_window_arrays` ships to device for this window.
+        """Host bytes `_window_arrays` ships to device for this window
+        (host latency rows at their row bucket ``Jr``, not ``Jp``).
 
         Device-resident latency rows (`DeviceLatencyOracle` path) are
         already on device — `stack_round_states` scatters them with a
@@ -604,27 +650,17 @@ class RoundProgram:
         return total
 
     def _window_arrays(self, window: RoundWindow):
-        return (
-            jnp.asarray(window.task_job),
-            jnp.asarray(window.perf_idx),
-            jnp.asarray(window.root_latency),
-            jnp.asarray(window.wait_s),
-            jnp.asarray(window.run_s),
-            jnp.asarray(window.cur_machine),
-            jnp.asarray(window.active),
-            jnp.asarray(window.free_slots),
-            jnp.asarray(window.scale),
-        )
-
-    def _params_scalars(self, params: PolicyParams) -> dict:
-        return dict(
-            p_m=jnp.int32(params.p_m),
-            p_r=jnp.int32(params.p_r),
-            omega=jnp.float32(params.omega),
-            gamma=jnp.float32(params.gamma),
-            preemption=jnp.bool_(params.preemption),
-            beta_scale=jnp.float32(params.beta_scale),
-        )
+        """The window's arrays on the device, in one batched transfer
+        (about 0.5 ms a round less than one transfer per array on a TPU
+        v5e), latency rows padded there to ``Jp``."""
+        arrs = list(jax.device_put((
+            window.task_job, window.perf_idx, window.root_latency,
+            window.wait_s, window.run_s, window.cur_machine, window.active,
+            window.free_slots, window.scale,
+        )))
+        if arrs[2].shape[1] < self.n_pad_jobs:
+            arrs[2] = _pad_rows(arrs[2], self.n_pad_jobs)
+        return tuple(arrs)
 
     def _record_window_spans(
         self,
@@ -691,7 +727,8 @@ class RoundProgram:
         Host-side validation (convergence, iteration caps, float32 cost
         bounds) happens around the dispatch, never inside it.
 
-        Spans: ``round_program.upload`` (the transfers), ``.dispatch`` (the
+        Spans: ``round_program.upload`` (the window's transfer and the
+        device-side latency-row pad), ``.dispatch`` (the
         jitted call returning), ``.sync`` (the iteration counts coming
         back: device run plus wait) and ``.fetch`` (the other results and
         the checks).
@@ -699,16 +736,15 @@ class RoundProgram:
         self._check_cost_bound(window)
         telemetry = obs.enabled()
         if telemetry:
-            obs.add("h2d.upload_bytes", self._window_upload_bytes(window))
+            self._count_upload(window)
             t0_ns = time.perf_counter_ns()
         with obs.span("round_program.upload"):
             arrs = self._window_arrays(window)
-            scalars = self._params_scalars(self.params)
             if telemetry:  # the span then measures the transfers
-                jax.block_until_ready((arrs, scalars))
+                jax.block_until_ready(arrs)
         with obs.span("round_program.dispatch"):
             new_state, (assigned, iters, cost, true_cost) = self._advance_jit(
-                state, arrs, scalars
+                state, arrs, self.scalars
             )
         with obs.span("round_program.sync"):
             if telemetry:
@@ -785,7 +821,7 @@ class RoundProgram:
         scale = int(window.scale[0])
         telemetry = obs.enabled()
         if telemetry:
-            obs.add("h2d.upload_bytes", self._window_upload_bytes(window))
+            self._count_upload(window)
             obs.add("whatif.lanes", K)
         with obs.span("round_program.upload"):
             arrs = self._window_arrays(window)

@@ -509,9 +509,25 @@ class WindowedAuctionBackend(AuctionBackend):
         """Compile + run the pinned R=1 window program on a synthetic
         round (see `RoundProgram.warmup`) so the serving loop's first real
         decision is a warm dispatch. Results-harmless: the warmup carry is
-        discarded, and exogenous windows never read carried occupancy."""
+        discarded, and exogenous windows never read carried occupancy.
+
+        Host latency rows reach the program at their own row bucket and
+        are padded to the pinned job bucket on the device, so without
+        device rows the round also runs once per row bucket 8, 16, ...
+        below the pin: each compiles its small pad program here, not in
+        the loop."""
         _key, prog = self._program(max(self._pin[0], 1), max(self._pin[1], 1))
-        prog.warmup(np.asarray(free_slots), root_latency=root_latency)
+        free_slots = np.asarray(free_slots)
+        prog.warmup(free_slots, root_latency=root_latency)
+        if root_latency is not None:
+            return
+        rows = 8
+        while rows < prog.n_pad_jobs:
+            prog.warmup(
+                free_slots,
+                root_latency=np.zeros((rows, prog.n_machines), np.float32),
+            )
+            rows *= 2
 
     def _program(self, n_tasks: int, n_jobs: int, *, chain: bool = False):
         from .round_program import RoundProgram

@@ -375,6 +375,93 @@ def test_windowed_backend_place_and_window_match_auction():
         assert ref.objective == p.objective
 
 
+@pytest.fixture(scope="module")
+def row_pad_backends():
+    """A windowed backend pinned at the (64, 32) bucket and an unpinned
+    one, shared by the cases below so each bucket compiles once."""
+    from repro.core.scheduler_backend import WindowedAuctionBackend
+
+    params = policy.PolicyParams(preemption=True)
+    pinned = WindowedAuctionBackend(
+        params, TOPO_PARTIAL, LUT, device=True, **_COSTMAP_KW
+    )
+    pinned.pin_serving(64, 32)
+    unpinned = WindowedAuctionBackend(
+        params, TOPO_PARTIAL, LUT, device=True, **_COSTMAP_KW
+    )
+    return pinned, unpinned
+
+
+@pytest.mark.parametrize(
+    "J", [3, 8, 32], ids=["below_row_bucket", "at_row_bucket", "at_job_bucket"]
+)
+@pytest.mark.parametrize("R", [1, 3])
+def test_host_latency_rows_padded_on_device(row_pad_backends, R, J):
+    """Host latency rows ship at their row bucket Jr and are padded to the
+    program's job bucket Jp on the device: the padded block is the host
+    zero-padded block bit for bit, ``h2d.latency_rows_skipped`` reads
+    R x (Jp - Jr), and a pinned backend places exactly as an unpinned one,
+    which skips no rows."""
+    from repro import obs
+    from repro.core.round_program import stack_round_states
+
+    pinned, unpinned = row_pad_backends
+    topo = TOPO_PARTIAL
+    M = topo.n_machines
+    rng = np.random.default_rng(100 + 10 * R + J)
+    states = [
+        _state(rng, topo, T=40, J=j, preempt_running=True)
+        for j in [J, max(1, J // 2), 1][:R]
+    ]
+    _key, prog = pinned._program(40, J)
+    Tp, Jp = prog.n_pad_tasks, prog.n_pad_jobs
+    assert (Tp, Jp) == (64, 32)
+    Jr = auction._bucket(J, 8)
+
+    window = stack_round_states(states, n_pad_tasks=Tp, n_pad_jobs=Jp)
+    assert window.root_latency.shape == (R, Jr, M)
+    host_block = np.zeros((R, Jp, M), np.float32)
+    for r, s in enumerate(states):
+        host_block[r, : s.n_jobs] = s.root_latency
+    device_block = np.asarray(prog._window_arrays(window)[2])
+    assert device_block.shape == host_block.shape
+    assert np.array_equal(
+        device_block.view(np.uint32), host_block.view(np.uint32)
+    )
+
+    with obs.scope():
+        prog.advance(prog.init_state(states[0].free_slots), window)
+        assert obs.counters()["h2d.latency_rows_skipped"] == R * (Jp - Jr)
+        assert obs.counters()["h2d.upload_bytes"] == prog._window_upload_bytes(
+            window
+        )
+
+    ctx = RoundContext(
+        rng=np.random.default_rng(0),
+        task_counts=np.zeros(M, np.int64),
+        n_ready=0,
+    )
+    variants = [
+        policy.PolicyParams(preemption=True, beta_scale=b)
+        for b in (0.0, 100.0 / 3600.0)
+    ]
+    placed = {}
+    for name, be in (("pinned", pinned), ("unpinned", unpinned)):
+        with obs.scope():
+            placed[name] = [be.place(s, ctx) for s in states] + [
+                be.place_whatif(states[0], ctx, variants)
+            ]
+            placed[name + "_skipped"] = obs.counters().get(
+                "h2d.latency_rows_skipped", 0.0
+            )
+    for a, b in zip(placed["pinned"], placed["unpinned"]):
+        assert np.array_equal(a.cols, b.cols)
+        assert a.objective == b.objective
+    expected = sum(Jp - auction._bucket(s.n_jobs, 8) for s in states)
+    assert placed["pinned_skipped"] == expected + Jp - Jr
+    assert placed["unpinned_skipped"] == 0.0
+
+
 def test_simulator_whatif_single_variant_matches_base():
     """whatif_betas with one variant equal to the configured beta is a
     no-op: the what-if dispatch returns the base placement bit for bit."""

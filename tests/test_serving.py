@@ -153,6 +153,28 @@ def test_serving_warm_path_zero_recompiles_and_replay_parity():
     assert len(svc.recorder.records) > 0
 
 
+def test_serving_warm_path_zero_recompiles_host_rows():
+    """The same contract on the host-row path (``device_latency=False``):
+    rounds of 1 to ~25 jobs under the pinned 64-job bucket ship only their
+    row bucket (8, 16 or 32 rows) and pad it on the device, and the pad
+    programs `warm_serving` compiled serve every one of them."""
+    from repro.core.auction import _bucket
+
+    with obs.scope():
+        svc = ScheduleService(dataclasses.replace(
+            SMOKE, backend="auction_windowed", record_rounds=10_000,
+            device_latency=False, warmup_rounds=1, rate_jobs_s=4.0,
+        ))
+        rep = svc.run()
+        skipped = obs.counters()["h2d.latency_rows_skipped"]
+    assert rep.drained
+    assert rep.jit_compiles_post_warmup == 0.0
+    assert rep.replay_mismatches == 0
+    row_buckets = {_bucket(s.n_jobs, 8) for s, _cols in svc.recorder.records}
+    assert row_buckets == {8, 16, 32}
+    assert skipped > 0
+
+
 # --------------------------------------------------------------------- #
 # SchedulerBackend protocol conformance
 
