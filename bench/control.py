@@ -36,9 +36,11 @@ def main(argv=None) -> int:
     a = p.parse_args(argv)
     try:
         bench_run._imports()
+        import check
         import harness
 
         cell = harness.Cell.load(bench_run.ROOT, a.workload)
+        check.load_reference(cell.config)  # refuses a config its reference cannot check
         jax = bench_run.configure_jax()
         dev = bench_run.device_info(jax, cell.chips, require_tpu=True)
     except bench_run.Refused as e:

@@ -25,6 +25,11 @@ computes the placement the paper's policy defines:
 All arithmetic is float32 / int32, as the configuration states.
 ``precision="bfloat16"`` computes the cost model (steps 1-5) in bfloat16
 instead: the control that a correct comparison must fail.
+
+It solves ``place`` rounds on the static plane, and implements neither of
+a configuration's optional blocks: a ``plane`` block's hotspots, regime
+shifts or storms change the rows of step 1, and a ``scheduler`` block
+turns on rounds of other kinds (``check.load_reference`` refuses both).
 """
 
 from __future__ import annotations
@@ -35,6 +40,9 @@ from typing import Optional
 import numpy as np
 
 F32 = np.float32
+
+KINDS = ("place",)  # the kinds of logged round this reference solves
+BLOCKS = ()  # the optional configuration blocks it implements
 
 # Paper Eqs. 2-5: (threshold_us, ascending polynomial coefficients), in
 # the program's model order (memcached, strads, spark, tensorflow).
@@ -300,9 +308,10 @@ def auction(
 def place(
     inp: RoundInputs, cluster: Cluster, policy: Policy, series: np.ndarray,
     plane_seed: int, precision: str = "float32",
-    costs: Optional[tuple] = None,
+    costs: Optional[tuple] = None, config: Optional[dict] = None,
 ) -> np.ndarray:
-    """The round's placement columns, per the paper's policy."""
+    """The round's placement columns, per the paper's policy. ``config``
+    is the run's configuration, which this reference needs no more of."""
     w, a = costs if costs is not None else round_costs(
         inp, cluster, policy, series, plane_seed, precision
     )

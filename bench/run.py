@@ -10,9 +10,11 @@ dot>.py``), each with ``read(observation) -> float | None``.
 A run builds the cluster, the latency plane and the jobs from ``--seed``,
 fills the cluster, warms every round program the window can reach (all
 of that is ``setup_s``), measures for ``--seconds``, then checks a
-seed-drawn sample of the window's rounds against the plain reference
-(`check.py`). ``--trace 1`` runs the same window under the JAX profiler
-with the program's telemetry on and reports the per-layer metrics.
+seed-drawn sample of the window's rounds against the configuration's
+plain reference (`check.py`; loaded, and checked to implement every block
+the configuration states, before set-up). ``--trace 1`` runs the same
+window under the JAX profiler with the program's telemetry on and reports
+the per-layer metrics.
 
 The last line of standard output is one JSON object with ``correct``,
 ``attempted``, ``failed``, ``metrics``, ``device`` (and ``breakdown`` in
@@ -134,27 +136,29 @@ def measure(cell, seed: int, seconds: float, trace: bool, compiles,
     with window_ctx():
         run = bench.run_window(compiles)
     say(f"[window] {run.window_s:.3f} s, {run.rounds} rounds, {run.sim_s:.1f} simulated s, "
-        f"{len(bench.log.rounds)} solver rounds logged, "
+        f"{len(bench.log.rounds)} solver rounds logged (by kind {bench.kinds_logged()}), "
         f"compiles in window: {run.compiles_in_window}; buckets (tasks, jobs): rounds "
         f"{dict(sorted(bench.buckets_used().items()))}")
     return bench, run, setup_s
 
 
 def compare(bench, seed: int, precision: Optional[str] = None, against: str = "tables") -> dict:
-    """The seed-drawn sample of the window's rounds against the reference,
-    computed in ``precision`` (default: the configuration's)."""
+    """The seed-drawn sample of the window's rounds against the
+    configuration's reference, computed in ``precision`` (default: the
+    configuration's)."""
     import check
-    import reference as ref
 
-    cell, tr = bench.cell, bench.cell.traffic
+    tr, cfg = bench.cell.traffic, bench.cell.config
+    ref = check.load_reference(cfg)
     picks = check.sample_rounds(
-        bench.log.rounds, seed, int(tr["check_rounds"]), int(tr.get("check_migration_rounds", 0))
+        bench.log.rounds, seed, int(tr["check_rounds"]), int(tr.get("check_migration_rounds", 0)),
+        kinds=ref.KINDS,
     )
-    cluster = ref.Cluster.from_dict(cell.config["topology"])
-    policy = ref.Policy.from_dict({**cell.config["solver"], **bench.policy})
-    stated = cell.config["solver"]["precision"]
-    return check.compare(bench.log.rounds, picks, cluster, policy, bench.plane.series,
-                         bench.plane_seed, precision or stated, against, stated)
+    cluster = ref.Cluster.from_dict(cfg["topology"])
+    policy = ref.Policy.from_dict({**cfg["solver"], **bench.policy})
+    stated = cfg["solver"]["precision"]
+    return check.compare(ref, bench.log.rounds, picks, cluster, policy, bench.plane.series,
+                         bench.plane_seed, precision or stated, against, stated, cfg)
 
 
 def run_cell(
@@ -166,11 +170,13 @@ def run_cell(
     _imports()
     import numpy as np
 
+    import check
     import harness
     import kernel_cost
     import trace_reduce
 
     cell = cell if cell is not None else harness.Cell.load(ROOT, workload)
+    check.load_reference(cell.config)  # refuses a config its reference cannot check
     jax = configure_jax()
     dev = device_info(jax, cell.chips, require_tpu)
     peak = kernel_cost.load_peak(dev["kind"]) if require_tpu else None
@@ -224,7 +230,8 @@ def run_cell(
     t_ref = time.perf_counter()
     res = compare(bench, seed)
     say(f"[check] {res['rounds']} rounds ({res['migration_rounds']} migration, "
-        f"{res['tasks']} tasks) vs the plain reference in {time.perf_counter() - t_ref:.2f} s: "
+        f"{res['tasks']} tasks; by kind {res['kinds']}) vs the plain reference in "
+        f"{time.perf_counter() - t_ref:.2f} s: "
         f"{res['mismatched_tasks']} mismatched tasks"
         + (f", first in logged round {res['first_bad_round']}" if res["mismatched_tasks"] else ""))
 

@@ -5,7 +5,8 @@
 They are not part of the repository's tier-1 run (``pytest.ini`` collects
 ``tests/`` only). ``tiny_cell`` builds a cell on a 256-machine cluster
 (``tiny.json``) under one of the real traffic mixes, with the arrival
-rate raised so that a few seconds hold some tens of rounds.
+rate raised so that a few seconds hold some tens of rounds; ``config``
+names another configuration file under ``bench/tests``.
 """
 
 import copy
@@ -36,11 +37,11 @@ def spec():
 def tiny_cell(spec):
     import harness
 
-    def make(mix: str, **traffic):
+    def make(mix: str, config: str = "tiny.json", **traffic):
         local = copy.deepcopy(spec)
         tr = _load(os.path.join(BENCH, "traffic", mix + ".json"))
         tr.update(traffic)
-        cfg = _load(os.path.join(BENCH, "tests", "tiny.json"))
+        cfg = _load(os.path.join(BENCH, "tests", config))
         name = f"tiny.{mix}"
         for m in local["end_to_end"] + local["per_layer"]:
             if "workloads" in m and any(w.endswith("." + mix) for w in m["workloads"]):
